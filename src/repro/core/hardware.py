@@ -132,6 +132,24 @@ CHIP_ALIASES = {
 }
 
 
+# ``device_kind`` as JAX reports it for an attached chip -> that chip's
+# spec.  A kind missing here is an error, never a default peak.
+DEVICE_KINDS = {
+    "TPU v5 lite": "tpu-v5e",
+}
+
+
+def chip_of_device_kind(kind: str) -> ChipSpec:
+    """>>> chip_of_device_kind("TPU v5 lite").name
+    'tpu-v5e'
+    """
+    try:
+        return CHIPS[DEVICE_KINDS[kind]]
+    except KeyError:
+        raise KeyError(f"device kind {kind!r} has no chip spec; known: "
+                       f"{sorted(DEVICE_KINDS)}") from None
+
+
 def get_chip(name: str) -> ChipSpec:
     """Resolve a chip/tier name (canonical or alias) to its spec.
 

@@ -10,6 +10,12 @@ systolic array).  GQA is handled in the index map (query head → KV head);
 sliding windows and causality by whole-tile skips first, intra-tile iota
 masks second.
 
+Operands are head-major — q (B, Hq, T, D), k/v (B, Hkv, S, D) — so every
+block's two minor dimensions are (tokens, head_dim): a multiple of 8 by the
+full head dimension, the tiling Mosaic requires on TPU.  A token-major
+layout would put a single head (block extent 1) in the sublane dimension,
+which the v5e compiler refuses.
+
 VMEM footprint per grid step ≈ (block_q + 2·block_k)·D·2B tiles +
 block_q·(block_k + D + 2)·4B scratch ≈ 230 KiB at the 128/128/D=128
 defaults — comfortably inside ~16 MiB v5e VMEM with double buffering.
@@ -30,8 +36,8 @@ NEG_INF = -1e30
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref,            # (1, bq, 1, D), (1, bk, 1, D), (1, bk, 1, D)
-    o_ref,                          # (1, bq, 1, D)
+    q_ref, k_ref, v_ref,            # (1, 1, bq, D), (1, 1, bk, D), (1, 1, bk, D)
+    o_ref,                          # (1, 1, bq, D)
     m_scr, l_scr, acc_scr,          # (bq, 1), (bq, 1), (bq, D) fp32 VMEM
     *,
     softmax_scale: float,
@@ -68,8 +74,8 @@ def _flash_kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * softmax_scale
-        k = k_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[0, 0].astype(jnp.float32) * softmax_scale
+        k = k_ref[0, 0].astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)           # (bq, bk)
@@ -89,7 +95,7 @@ def _flash_kernel(
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        v = v_ref[0, 0].astype(jnp.float32)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -98,7 +104,7 @@ def _flash_kernel(
     def _finalize():
         l = l_scr[...]
         l = jnp.where(l == 0.0, 1.0, l)                    # fully-masked rows
-        o_ref[0, :, 0, :] = (acc_scr[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] / l).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -115,9 +121,9 @@ def flash_attention(
     block_k: int = 128,
     interpret: bool = False,
 ):
-    """q: (B, T, Hq, D); k, v: (B, S, Hkv, D) -> (B, T, Hq, D)."""
-    B, T, Hq, D = q.shape
-    S, Hkv = k.shape[1], k.shape[2]
+    """q: (B, Hq, T, D); k, v: (B, Hkv, S, D) -> (B, Hq, T, D)."""
+    B, Hq, T, D = q.shape
+    Hkv, S = k.shape[1], k.shape[2]
     assert Hq % Hkv == 0, (Hq, Hkv)
     G = Hq // Hkv
     scale = softmax_scale if softmax_scale is not None else 1.0 / math.sqrt(D)
@@ -127,10 +133,10 @@ def flash_attention(
     Tp = -(-T // block_q) * block_q
     Sp = -(-S // block_k) * block_k
     if Tp != T:
-        q = jnp.pad(q, ((0, 0), (0, Tp - T), (0, 0), (0, 0)))
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, Tp - T), (0, 0)))
     if Sp != S:
-        k = jnp.pad(k, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, Sp - S), (0, 0), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
 
     grid = (B, Hq, Tp // block_q, Sp // block_k)
     kernel = functools.partial(
@@ -142,12 +148,12 @@ def flash_attention(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, D), lambda b, h, it, ik: (b, it, h, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, it, ik: (b, ik, h // G, 0)),
-            pl.BlockSpec((1, block_k, 1, D), lambda b, h, it, ik: (b, ik, h // G, 0)),
+            pl.BlockSpec((1, 1, block_q, D), lambda b, h, it, ik: (b, h, it, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, it, ik: (b, h // G, ik, 0)),
+            pl.BlockSpec((1, 1, block_k, D), lambda b, h, it, ik: (b, h // G, ik, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, D), lambda b, h, it, ik: (b, it, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, Tp, Hq, D), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, it, ik: (b, h, it, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Tp, D), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
@@ -155,4 +161,4 @@ def flash_attention(
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :T]
+    return out[:, :, :T]

@@ -12,8 +12,11 @@ sequence's ``context_len`` are skipped with ``pl.when`` — the DMA still
 fetches the (arbitrary) page the table points at, so callers should point
 unused slots at a valid page id (0 is fine).
 
-Layout choice: K/V pool is (num_pages, page_size, Hkv, D) with page_size a
-multiple of 8 so each (page_size, D) tile is rank-2 MXU/VPU friendly.
+Layout choice: the K/V pool is head-major, (Hkv, num_pages, page_size, D),
+with page_size a multiple of 8, so each block's two minor dimensions are a
+whole (page_size, D) tile — the tiling Mosaic requires on TPU.  A
+token-major pool would put a single KV head in the sublane dimension, which
+the v5e compiler refuses.
 """
 
 from __future__ import annotations
@@ -36,7 +39,7 @@ def _paged_kernel(
     context_lens_ref,               # (B,) int32, SMEM
     # blocks:
     q_ref,                          # (1, 1, G, D)
-    k_ref, v_ref,                   # (1, page_size, 1, D)
+    k_ref, v_ref,                   # (1, 1, page_size, D)
     o_ref,                          # (1, 1, G, D)
     m_scr, l_scr, acc_scr,          # (G, 1), (G, 1), (G, D)
     *,
@@ -59,7 +62,7 @@ def _paged_kernel(
     @pl.when(page_start < ctx)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * softmax_scale      # (G, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)                # (P, D)
+        k = k_ref[0, 0].astype(jnp.float32)                      # (P, D)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)                  # (G, P)
@@ -71,7 +74,7 @@ def _paged_kernel(
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)                # (P, D)
+        v = v_ref[0, 0].astype(jnp.float32)                      # (P, D)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
         m_scr[...] = m_new
@@ -93,13 +96,13 @@ def paged_attention(
     """Decode attention over a paged KV pool.
 
     q:            (B, Hq, D)
-    k/v_pages:    (num_pages, page_size, Hkv, D)
+    k/v_pages:    (Hkv, num_pages, page_size, D)
     block_tables: (B, pages_per_seq) int32 (unused slots -> any valid page)
     context_lens: (B,) int32
     returns       (B, Hq, D)
     """
     B, Hq, D = q.shape
-    num_pages, page_size, Hkv, _ = k_pages.shape
+    Hkv, num_pages, page_size, _ = k_pages.shape
     pages_per_seq = block_tables.shape[1]
     assert Hq % Hkv == 0
     G = Hq // Hkv
@@ -118,10 +121,10 @@ def paged_attention(
             grid=grid,
             in_specs=[
                 pl.BlockSpec((1, 1, G, D), lambda b, h, ip, bt, cl: (b, h, 0, 0)),
-                pl.BlockSpec((1, page_size, 1, D),
-                             lambda b, h, ip, bt, cl: (bt[b, ip], 0, h, 0)),
-                pl.BlockSpec((1, page_size, 1, D),
-                             lambda b, h, ip, bt, cl: (bt[b, ip], 0, h, 0)),
+                pl.BlockSpec((1, 1, page_size, D),
+                             lambda b, h, ip, bt, cl: (h, bt[b, ip], 0, 0)),
+                pl.BlockSpec((1, 1, page_size, D),
+                             lambda b, h, ip, bt, cl: (h, bt[b, ip], 0, 0)),
             ],
             out_specs=pl.BlockSpec((1, 1, G, D),
                                    lambda b, h, ip, bt, cl: (b, h, 0, 0)),

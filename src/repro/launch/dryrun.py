@@ -104,8 +104,6 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, out_path: str,
     if verbose:
         print(compiled.memory_analysis())   # proves it fits
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # jax 0.4.x: one dict per device
-        cost = cost[0] if cost else {}
     if verbose:
         print({k: cost[k] for k in ("flops", "bytes accessed")
                if k in cost})

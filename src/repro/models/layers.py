@@ -3,9 +3,12 @@
 Everything here is a pure function over explicit parameter pytrees — no
 framework, no globals — so the same code path serves:
 
-* real-mode execution on CPU (serving fidelity benchmarks),
-* TPU execution (where `repro.kernels.*.ops` swap in Pallas kernels),
+* real-mode execution (serving fidelity runs; reduced models on the CPU in
+  tests, published widths on a TPU),
 * abstract lowering for the multi-pod dry-run (ShapeDtypeStruct inputs).
+
+No layer calls the Pallas kernels in ``repro.kernels`` yet: attention and the
+SSD scan here are plain-JAX lowerings on every backend.
 
 Conventions:
   B batch, T query tokens, S KV length, H heads, Hkv KV heads, D head_dim,
@@ -102,7 +105,7 @@ def rope(x, positions, theta: float):
 
 
 # --------------------------------------------------------------------------
-# attention (reference; Pallas kernels override on TPU via repro.kernels)
+# attention (plain JAX; the Pallas kernels are not wired in)
 # --------------------------------------------------------------------------
 
 def attention(q, k, v, mask, *, softmax_scale: Optional[float] = None,
@@ -359,12 +362,7 @@ def moe_a2a(cfg: ModelConfig, p, x):
     n_loc = B_loc * T_loc
     cap = max(1, int(math.ceil(n_loc * K / ep * moe_cfg.capacity_factor)))
 
-    try:
-        from jax import shard_map               # jax >= 0.6
-        _check_kw = {"check_vma": False}
-    except ImportError:                         # jax 0.4/0.5 experimental API
-        from jax.experimental.shard_map import shard_map
-        _check_kw = {"check_rep": False}
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     x_spec = P(b_spec, "model", None)
@@ -433,7 +431,7 @@ def moe_a2a(cfg: ModelConfig, p, x):
         in_specs=(x_spec, P(None, None), P("model", None, None),
                   P("model", None, None)),
         out_specs=(x_spec, P(), P()),
-        **_check_kw,
+        check_vma=False,
     )
     y, aux, ce = fn(x, p["router"], p["w_in"], p["w_out"])
     return y, {"moe_aux_loss": aux, "expert_load": ce}

@@ -447,6 +447,15 @@ class TransformerLM:
         """tokens: (B, 1) -> (logits (B, V), new cache)."""
         return self.prefill(params, {"tokens": tokens}, cache)
 
+    def forward(self, params, tokens):
+        """Cache-free pass over whole sequences: tokens (B, T) -> logits
+        (B, T, V) at every position.  The reference the cached serving
+        path (chunked prefill, then decode) is checked against."""
+        x, positions = self._embed_inputs(params, {"tokens": tokens})
+        x, _, _ = self._run_stack(params, x, positions, None)
+        return self._unembed(
+            params, L.apply_norm(self.cfg, x, params["final_norm"]))
+
 
 # ==========================================================================
 # encoder-decoder (whisper)

@@ -423,6 +423,10 @@ class BenchmarkRunner:
                 # apply deterministically, not race teardown — the DES drains
                 # its heap unconditionally and the fault logs are compared
                 self.fault_injector.join()
+        except Exception:
+            if started_here:              # the target's loop failed: reap it
+                self.target.stop()
+            raise
         finally:
             if self.fault_injector is not None:
                 self.fault_injector.stop()

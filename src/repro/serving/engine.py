@@ -91,6 +91,8 @@ class LLMEngine:
         # set by force_kill (crash injection): the loop thread swallows the
         # unwedge exception from its aborted jump and exits immediately
         self._killed = threading.Event()
+        # the exception that ended the step loop; waiters re-raise it
+        self.error: Optional[BaseException] = None
         self.finished: List[Request] = []
         self.step_log: List[StepRecord] = []
         self._finish_cond = threading.Condition()
@@ -336,12 +338,15 @@ class LLMEngine:
 
             try:
                 self.step()
-            except Exception:
+            except Exception as exc:
                 # force_kill retires the worker actor out from under a
                 # blocked jump; the client raises (KeyError) — that is the
                 # expected unwedge path, not an error
                 if self._killed.is_set():
                     break
+                with self._finish_cond:
+                    self.error = exc
+                    self._finish_cond.notify_all()
                 raise
         # drain: mark idle so waiters exit
         self._idle.set()
@@ -409,9 +414,13 @@ class LLMEngine:
 
     # ----------------------------------------------------------- waiting --
     def wait_until_complete(self, expected: int, timeout: float = 600.0) -> bool:
+        """True once ``expected`` requests finished, False at the timeout;
+        re-raises the exception that ended the step loop, if one did."""
         deadline = time.monotonic() + timeout
         with self._finish_cond:
             while self._finished_count < expected:
+                if self.error is not None:
+                    raise self.error
                 remaining = deadline - time.monotonic()
                 if remaining <= 0:
                     return False

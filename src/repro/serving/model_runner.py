@@ -5,8 +5,9 @@ This boundary is the JAX analogue of vLLM/SGLang's CUDA call sites, and the
 onboard a serving system" — here it is the :class:`TimeWarpModelRunner`):
 
 * :class:`RealModelRunner` — executes the actual JAX model (ground truth for
-  the fidelity benchmarks; CPU here, TPU in production).  Also doubles as
-  the profiler that fits the :class:`~repro.core.predictor.TablePredictor`.
+  the fidelity benchmarks; a reduced model on the CPU in tests, published
+  widths on a TPU).  Also doubles as the profiler whose step samples fit a
+  predictor.
 * :class:`TimeWarpModelRunner` — Revati: predicts the step duration and
   requests a TIMEJUMP instead of executing.  Weights and KV pool are
   ComputeBuffers in the VirtualDeviceContext (split-state memory model);
@@ -21,7 +22,6 @@ identical across modes by construction.
 
 from __future__ import annotations
 
-import threading
 import time
 from typing import Dict, List, Optional
 
@@ -163,19 +163,62 @@ class SleepModelRunner:
     def shutdown(self) -> None: ...
 
 
+def _slot_axis(key: str, uniform) -> int:
+    """Batch axis of a cache entry: stacked layers lead with the layer axis,
+    as do the enc-dec cross-attention K/V."""
+    if key in ("cross_k", "cross_v"):
+        return 1
+    if key == "layers" and uniform is not None:
+        return 1
+    return 0
+
+
+def _take_slot(uniform, cache, slot):
+    import jax
+    return {key: jax.tree.map(
+        lambda x, a=_slot_axis(key, uniform):
+            jax.lax.dynamic_slice_in_dim(x, slot, 1, a), sub)
+        for key, sub in cache.items()}
+
+
+def _put_slot(uniform, cache, small, slot):
+    import jax
+    return {key: jax.tree.map(
+        lambda big, x, a=_slot_axis(key, uniform):
+            jax.lax.dynamic_update_slice_in_dim(
+                big, x.astype(big.dtype), slot, a), sub, small[key])
+        for key, sub in cache.items()}
+
+
+def _prefill_slot(model, params, cache, slot, tokens, positions):
+    """One prompt chunk of the sequence in ``slot``: read its cache rows,
+    extend them, write them back (the shared cache is donated, so XLA
+    updates it in place)."""
+    uniform = getattr(model, "uniform", "x")
+    small = _take_slot(uniform, cache, slot)
+    logits, small = model.prefill(
+        params, {"tokens": tokens, "positions": positions}, small)
+    return logits, _put_slot(uniform, cache, small, slot)
+
+
 class RealModelRunner:
     """Executes the actual JAX model — ground truth for fidelity runs.
 
     Slot-based execution with fixed shapes (no recompilation in steady
-    state): a shared decode cache holds ``max_seqs`` slots; prefill chunks
-    run per-sequence (batch 1, bucketed chunk lengths) and their KV is
-    scattered into the slot cache.  Mixed batches execute as
-    prefill-calls + one batched decode call; the wall-clock sum is the
-    step's real duration (recorded for TablePredictor calibration).
+    state): a shared cache holds ``max_seqs`` slots of ``max_len``
+    positions plus a scratch region; prefill chunks run per sequence
+    (batch 1, bucketed chunk lengths) inside one jitted program that reads
+    and writes the sequence's slot in place.  Mixed batches execute as
+    prefill calls + one batched decode call; the wall-clock sum, taken once
+    the device has finished everything the step produced, is the step's
+    real duration (recorded for predictor calibration).  The cache takes
+    the weights' dtype.
     """
 
     def __init__(self, model, params, *, max_seqs: int, max_len: int,
                  clock: VirtualClock, chunk_buckets=(32, 64, 128, 256, 512)):
+        import functools
+
         import jax
         import jax.numpy as jnp
 
@@ -185,6 +228,7 @@ class RealModelRunner:
         self.max_len = max_len
         self.clock = clock
         self.chunk_buckets = tuple(sorted(chunk_buckets))
+        self.dtype = params["embed"].dtype
         # Padded prefill is only sound for pure-attention stacks (pad KV is
         # position-masked).  Recurrent blocks (SSD / RG-LRU) would fold pad
         # tokens into their state, so those archs run exact-length chunks
@@ -193,146 +237,116 @@ class RealModelRunner:
         self._pad_prefill = kinds <= {"attn", "local_attn"}
         self._jax = jax
         self._jnp = jnp
-        self._slack = self.chunk_buckets[-1]
-        self.cache = model.init_cache(max_seqs, max_len, jnp.float32,
-                                      window_slack=self._slack)
+        # Positions from max_len on are scratch: pad tokens and the decode
+        # rows of idle slots are written there, past every real position,
+        # so causal masking hides them from every real query.
+        slack = self.chunk_buckets[-1]
+        self._empty = model.init_cache(1, max_len, self.dtype,
+                                       window_slack=slack)
+        self.cache = model.init_cache(max_seqs, max_len, self.dtype,
+                                      window_slack=slack)
         self._slot_of: Dict[int, int] = {}
         self._free_slots = list(range(max_seqs))[::-1]
-        self._axes = self._cache_batch_axes()
         self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
-        self._prefill = jax.jit(model.prefill)
+        self._prefill = jax.jit(functools.partial(_prefill_slot, model),
+                                donate_argnums=(1,))
+        self._reset = jax.jit(
+            functools.partial(_put_slot, getattr(model, "uniform", "x")),
+            donate_argnums=(0,))
+        self._argmax = jax.jit(lambda logits: jnp.argmax(logits, axis=-1))
         self.samples: List[tuple] = []       # (BatchSpec, seconds) for fitting
-        self._pending_tokens: Dict[int, int] = {}
 
     # ------------------------------------------------------------ warmup --
     def warmup(self) -> None:
-        """Compile every steady-state shape (prefill buckets + the batched
-        decode) outside measured time.  Without this, first-call XLA compiles
-        (seconds) land inside step timings and poison both the TablePredictor
-        calibration and the fidelity comparison — the real-hardware analogue
-        of excluding warmup iterations from profiling."""
-        jax, jnp = self._jax, self._jnp
-        import numpy as np
+        """Compile every steady-state program (prefill buckets, slot reset,
+        the batched decode, sampling) outside measured time.  Without this,
+        first-call XLA compiles (seconds) land inside step timings and
+        poison both the predictor calibration and the fidelity comparison —
+        the real-hardware analogue of excluding warmup iterations from
+        profiling.  Everything it writes lands in the scratch region."""
         cfg = self.model.cfg
+        slot = np.int32(0)
+        self.cache = self._reset(self.cache, self._empty, slot)
         if self._pad_prefill and cfg.frontend is None:
-            empty = self.model.init_cache(1, self.max_len, jnp.float32,
-                                          window_slack=self._slack)
             for b in self.chunk_buckets:
-                toks = jnp.zeros((1, b), jnp.int32)
-                pos = jnp.asarray(np.arange(b)[None], jnp.int32)
-                small = dict(empty)
-                small["cache_len"] = jnp.asarray([0], jnp.int32)
-                self._prefill(self.params,
-                              {"tokens": toks, "positions": pos}, small)
-        toks = jnp.zeros((self.max_seqs, 1), jnp.int32)
-        _, donated = self._decode(self.params, self.cache, toks)
-        jax.block_until_ready(donated["cache_len"])
-        # decode warmup stamped pos-0 tags into every slot; rebuild the pool
-        self.cache = self.model.init_cache(self.max_seqs, self.max_len,
-                                           jnp.float32,
-                                           window_slack=self._slack)
+                toks = np.zeros((1, b), np.int32)
+                pos = (self.max_len + np.arange(b, dtype=np.int32))[None]
+                logits, self.cache = self._prefill(
+                    self.params, self.cache, slot, toks, pos)
+                self._argmax(logits)
+        logits = self.decode({})
+        self._jax.block_until_ready((self._argmax(logits), self.cache))
 
-    # ---------------------------------------------------- cache plumbing --
-    def _cache_batch_axes(self) -> Dict[str, int]:
-        axes = {"cache_len": 0}
-        uniform = getattr(self.model, "uniform", "x")
-        axes["layers"] = 0 if uniform is None else 1
-        axes["cross_k"] = 1
-        axes["cross_v"] = 1
-        return axes
+    # ------------------------------------------------------ device steps --
+    def acquire(self, request_id: int) -> int:
+        """Give ``request_id`` a slot, cleared of any earlier sequence."""
+        slot = self._free_slots.pop()
+        self._slot_of[request_id] = slot
+        self.cache = self._reset(self.cache, self._empty, np.int32(slot))
+        return slot
 
-    def _write_slot(self, slot: int, small_cache) -> None:
-        """Scatter a batch-1 cache into slot ``slot`` of the shared cache."""
-        jnp = self._jnp
-        for key, sub in small_cache.items():
-            ax = self._axes.get(key, 0)
-            def put(big, small):
-                idx = [slice(None)] * big.ndim
-                idx[ax] = slice(slot, slot + 1)
-                return big.at[tuple(idx)].set(small.astype(big.dtype))
-            self.cache[key] = self._jax.tree.map(put, self.cache[key], sub)
+    def prefill_chunk(self, slot: int, chunk: List[int], start: int):
+        """Extend the sequence in ``slot`` by ``chunk`` at positions
+        ``start``...; returns the logits (1, V) of the chunk's last token.
 
-    def _slot_cache(self, slot: int):
-        def take(big, ax):
-            idx = [slice(None)] * big.ndim
-            idx[ax] = slice(slot, slot + 1)
-            return big[tuple(idx)]
-        return {
-            key: self._jax.tree.map(lambda x, a=self._axes.get(key, 0): take(x, a), sub)
-            for key, sub in self.cache.items()
-        }
+        The chunk is left-padded up to its bucket: pad tokens come first, at
+        scratch positions, so the model's last row is the last real token
+        and a final chunk yields the request's true first token."""
+        if self._pad_prefill:
+            bucket = next((b for b in self.chunk_buckets if b >= len(chunk)),
+                          len(chunk))
+        else:
+            bucket = len(chunk)
+        pad = bucket - len(chunk)
+        toks = np.asarray([0] * pad + list(chunk), np.int32)[None]
+        positions = np.concatenate([
+            self.max_len + np.arange(pad),
+            start + np.arange(len(chunk))]).astype(np.int32)[None]
+        logits, self.cache = self._prefill(
+            self.params, self.cache, np.int32(slot), toks, positions)
+        return logits
+
+    def decode(self, feeds: Dict[int, tuple]):
+        """One batched decode over every slot.  ``feeds`` maps slot ->
+        (token, position); idle slots decode a throwaway token into the
+        scratch region.  Returns logits (max_seqs, V)."""
+        tokens = np.zeros((self.max_seqs, 1), np.int32)
+        positions = np.full((self.max_seqs,), self.max_len, np.int32)
+        for slot, (tok, pos) in feeds.items():
+            tokens[slot, 0] = tok
+            positions[slot] = pos
+        self.cache["cache_len"] = self._jnp.asarray(positions)
+        logits, self.cache = self._decode(self.params, self.cache, tokens)
+        return logits
 
     # ------------------------------------------------------------ running --
     def execute(self, out: SchedulerOutput) -> Dict[int, int]:
-        jax, jnp = self._jax, self._jnp
         t0 = time.monotonic()
-        tokens: Dict[int, int] = {}
-
-        prefills = [s for s in out.batch if s.is_prefill]
-        decodes = [s for s in out.batch if not s.is_prefill]
-
-        # ---- prefill chunks, per sequence, bucketed lengths ----
-        for s in prefills:
+        firsts = []                          # (request_id, device argmax)
+        for s in out.batch:
+            if not s.is_prefill:
+                continue
             req = s.request
             slot = self._slot_of.get(req.request_id)
             if slot is None:
-                slot = self._free_slots.pop()
-                self._slot_of[req.request_id] = slot
-                # zero the slot
-                empty = self.model.init_cache(1, self.max_len, jnp.float32,
-                                              window_slack=self._slack)
-                self._write_slot(slot, {k: empty[k] for k in empty})
+                slot = self.acquire(req.request_id)
             start = req.num_prefilled
-            chunk = list(req.prompt_tokens[start : start + s.num_new_tokens])
-            if self._pad_prefill:
-                bucket = next((b for b in self.chunk_buckets if b >= len(chunk)),
-                              len(chunk))
-            else:
-                bucket = len(chunk)
-            pad = bucket - len(chunk)
-            toks = jnp.asarray(chunk + [0] * pad, jnp.int32)[None]
-            # pad positions land in the scratch region past max_len: they are
-            # masked for every real query (pos > any q_pos) and their ring
-            # slots never alias live context.
-            real_pos = start + np.arange(len(chunk))
-            pad_pos = self.max_len + np.arange(pad)
-            positions = jnp.asarray(
-                np.concatenate([real_pos, pad_pos])[None], jnp.int32)
-            small = self._slot_cache(slot)
-            # correct cache_len for padding: advance only by real chunk
-            small["cache_len"] = jnp.asarray([start], jnp.int32)
-            logits, new_small = self._prefill(
-                self.params, {"tokens": toks, "positions": positions}, small)
-            new_small["cache_len"] = jnp.asarray([start + len(chunk)], jnp.int32)
-            self._write_slot(slot, new_small)
+            chunk = req.prompt_tokens[start : start + s.num_new_tokens]
+            logits = self.prefill_chunk(slot, chunk, start)
             if start + len(chunk) >= req.prompt_len:
-                # padded garbage may occupy ring slots > prompt end; for the
-                # fidelity workloads prompts are block-aligned so pad == 0 in
-                # the final chunk, and logits are the true first token.
-                tokens[req.request_id] = int(jnp.argmax(logits[0]))
+                firsts.append((req.request_id, self._argmax(logits)))
 
-        # ---- batched decode over the shared slot cache ----
-        if decodes:
-            step_tokens = np.zeros((self.max_seqs, 1), np.int32)
-            for s in decodes:
-                req = s.request
-                slot = self._slot_of[req.request_id]
-                last = (req.output_tokens[-1] if req.output_tokens
-                        else self._pending_tokens.get(req.request_id, 0))
-                step_tokens[slot, 0] = last
-            # cache_len per slot must reflect each sequence's context
-            cl = np.zeros((self.max_seqs,), np.int32)
-            for s in decodes:
-                cl[self._slot_of[s.request.request_id]] = s.request.context_len
-            self.cache["cache_len"] = jnp.asarray(cl)
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(step_tokens))
-            picked = np.asarray(jnp.argmax(logits, axis=-1))
-            for s in decodes:
-                slot = self._slot_of[s.request.request_id]
-                tokens[s.request.request_id] = int(picked[slot])
+        # The newest token is counted in context_len but not yet cached: it
+        # is fed at position context_len - 1.
+        decodes = [s.request for s in out.batch if not s.is_prefill]
+        feeds = {self._slot_of[r.request_id]:
+                 (r.output_tokens[-1], r.context_len - 1) for r in decodes}
+        picked = np.asarray(self._argmax(self.decode(feeds))) if feeds else ()
 
-        jax.block_until_ready(self.cache["cache_len"])
+        tokens = {rid: int(np.asarray(t)[0]) for rid, t in firsts}
+        for r in decodes:
+            tokens[r.request_id] = int(picked[self._slot_of[r.request_id]])
+        self._jax.block_until_ready(self.cache)
         dt = time.monotonic() - t0
         self.samples.append((batch_spec_of(out), dt))
 
@@ -341,9 +355,7 @@ class RealModelRunner:
             req = s.request
             if (not s.is_prefill and
                     req.num_generated + 1 >= req.max_new_tokens):
-                slot = self._slot_of.pop(req.request_id, None)
-                if slot is not None:
-                    self._free_slots.append(slot)
+                self.release(req.request_id)
         return tokens
 
     def release(self, request_id: int) -> None:

@@ -15,11 +15,7 @@ def compile_(fn, *args, donate=()):
 
 
 def xla_cost(compiled) -> dict:
-    """Normalize cost_analysis across jax versions (0.4.x returns [dict])."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        return ca[0] if ca else {}
-    return ca
+    return compiled.cost_analysis()
 
 
 def test_matches_xla_on_scanfree_mlp():
@@ -102,15 +98,10 @@ def test_collectives_weighted_by_trip_count():
             return jax.lax.psum(h @ w, "model"), None
         return jax.lax.scan(body, x, ws)[0]
 
-    try:
-        from jax import shard_map               # jax >= 0.6
-        check_kw = {"check_vma": False}
-    except ImportError:                         # jax 0.4/0.5 experimental API
-        from jax.experimental.shard_map import shard_map
-        check_kw = {"check_rep": False}
+    from jax import shard_map
     f = shard_map(scanned_psum, mesh=mesh,
                   in_specs=(P(None, None), P(None, None, None)),
-                  out_specs=P(None, None), **check_kw)
+                  out_specs=P(None, None), check_vma=False)
     x = jax.ShapeDtypeStruct((64, 64), jnp.float32)
     ws = jax.ShapeDtypeStruct((5, 64, 64), jnp.float32)
     m = analyze_hlo(compile_(f, x, ws).as_text())

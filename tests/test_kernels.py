@@ -1,5 +1,6 @@
-"""Pallas kernel validation (assignment requirement): sweep shapes/dtypes and
-assert_allclose each kernel (interpret=True on CPU) against its ref.py oracle.
+"""Pallas kernel validation: sweep shapes/dtypes and assert_allclose each
+kernel (interpret=True on CPU) against its ref.py oracle.  Operands are in
+the kernels' head-major layouts.
 """
 
 import math
@@ -24,9 +25,9 @@ TOL32 = dict(rtol=2e-5, atol=2e-5)
 
 def _qkv(key, B, T, S, Hq, Hkv, D, dtype):
     kq, kk, kv = jax.random.split(key, 3)
-    q = jax.random.normal(kq, (B, T, Hq, D), jnp.float32).astype(dtype)
-    k = jax.random.normal(kk, (B, S, Hkv, D), jnp.float32).astype(dtype)
-    v = jax.random.normal(kv, (B, S, Hkv, D), jnp.float32).astype(dtype)
+    q = jax.random.normal(kq, (B, Hq, T, D), jnp.float32).astype(dtype)
+    k = jax.random.normal(kk, (B, Hkv, S, D), jnp.float32).astype(dtype)
+    v = jax.random.normal(kv, (B, Hkv, S, D), jnp.float32).astype(dtype)
     return q, k, v
 
 
@@ -104,9 +105,9 @@ def _paged_inputs(key, B, Hq, Hkv, D, page_size, pages_per_seq, dtype,
     num_pages = num_pages or (B * pages_per_seq + 1)
     q = jax.random.normal(kq, (B, Hq, D), jnp.float32).astype(dtype)
     k_pages = jax.random.normal(
-        kk, (num_pages, page_size, Hkv, D), jnp.float32).astype(dtype)
+        kk, (Hkv, num_pages, page_size, D), jnp.float32).astype(dtype)
     v_pages = jax.random.normal(
-        kv, (num_pages, page_size, Hkv, D), jnp.float32).astype(dtype)
+        kv, (Hkv, num_pages, page_size, D), jnp.float32).astype(dtype)
     # each sequence owns a disjoint page range (as the BlockManager produces)
     tables = np.arange(B * pages_per_seq, dtype=np.int32).reshape(B, pages_per_seq)
     max_ctx = page_size * pages_per_seq
@@ -178,9 +179,9 @@ def test_paged_property_sweep(B, Hkv, G, page, pps):
 
 def _ssd_inputs(key, B, T, H, P, N, dtype=jnp.float32):
     kx, ka, kb, kc = jax.random.split(key, 4)
-    xdt = jax.random.normal(kx, (B, T, H, P), jnp.float32).astype(dtype)
+    xdt = jax.random.normal(kx, (B, H, T, P), jnp.float32).astype(dtype)
     # realistic decays: dA = -softplus(...) in (−∞, 0); keep moderate
-    dA = -jax.nn.softplus(jax.random.normal(ka, (B, T, H), jnp.float32))
+    dA = -jax.nn.softplus(jax.random.normal(ka, (B, H, T), jnp.float32))
     Bm = jax.random.normal(kb, (B, T, N), jnp.float32).astype(dtype)
     Cm = jax.random.normal(kc, (B, T, N), jnp.float32).astype(dtype)
     return xdt, dA.astype(dtype), Bm, Cm
@@ -217,11 +218,11 @@ def test_ssd_state_continuation():
     carried state (the property chunked prefill of SSM archs relies on)."""
     xdt, dA, Bm, Cm = _ssd_inputs(jax.random.key(2), 1, 256, 2, 32, 32)
     y_full, s_full = ref.ssd_scan_ref(xdt, dA, Bm, Cm)
-    y_a, s_a = ref.ssd_scan_ref(xdt[:, :128], dA[:, :128],
+    y_a, s_a = ref.ssd_scan_ref(xdt[:, :, :128], dA[:, :, :128],
                                 Bm[:, :128], Cm[:, :128])
-    y_b, s_b = ref.ssd_scan_ref(xdt[:, 128:], dA[:, 128:],
+    y_b, s_b = ref.ssd_scan_ref(xdt[:, :, 128:], dA[:, :, 128:],
                                 Bm[:, 128:], Cm[:, 128:], initial_state=s_a)
-    np.testing.assert_allclose(np.asarray(y_full[:, 128:]), np.asarray(y_b),
+    np.testing.assert_allclose(np.asarray(y_full[:, :, 128:]), np.asarray(y_b),
                                rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(s_full), np.asarray(s_b),
                                rtol=1e-4, atol=1e-4)
