@@ -41,6 +41,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 from repro.core.clock import VirtualClock
+from repro.core.spans import span
 
 from .kv_cache import BlockManager
 from .prefix_cache import RadixPrefixCache
@@ -50,13 +51,34 @@ from .scheduler import EngineConfig, Scheduler, SchedulerOutput
 
 @dataclass
 class StepRecord:
+    """One engine step.  Times are on the engine's clock (``t_start``,
+    ``t_end``, ``device_time``) or host wall seconds (the rest).
+
+    ``device_time`` is ``t_end - t_start``: scheduling, then the runner's
+    ``execute``.  In emulated modes that is the scheduler's time plus the
+    predicted jump; in real mode it is the whole wall time of scheduling
+    and ``execute`` (host work plus waiting on the device), not the time
+    the device spent computing.
+
+    ``cpu_overhead_wall`` is the engine's host time, ``sched_s`` (scheduling
+    and releasing preempted requests) plus ``post_s`` (``on_step_complete``,
+    releases, completion listeners).  ``runner_host_s`` and
+    ``runner_wait_s`` split the runner's ``execute`` into its own host work
+    and the time the host sat blocked on the device (``last_phases`` of the
+    real runner; zero for emulated runners).  The four phase fields default
+    to zero, so older pickled step logs still load.
+    """
     t_start: float
     t_end: float
     num_prefill_tokens: int
     num_decode: int
     batch_size: int
-    cpu_overhead_wall: float     # scheduler+bookkeeping wall seconds
-    device_time: float           # executed/jumped seconds
+    cpu_overhead_wall: float
+    device_time: float
+    sched_s: float = 0.0
+    post_s: float = 0.0
+    runner_host_s: float = 0.0
+    runner_wait_s: float = 0.0
 
 
 class LLMEngine:
@@ -306,35 +328,39 @@ class LLMEngine:
 
     def run_loop(self) -> None:
         while not self._stop.is_set():
-            # Drain + scheduler-add under one _state_lock acquisition: a
-            # snapshot() between the two would otherwise catch the drained
-            # requests in neither inbox nor scheduler and silently lose them.
-            with self._state_lock:
-                with self._lock:
-                    new = self._inbox
-                    self._inbox = []
-                for req in new:
-                    self.scheduler.add_request(req)
+            with span("revati.engine.loop"):
+                # Drain + scheduler-add under one _state_lock acquisition: a
+                # snapshot() between the two would otherwise catch the
+                # drained requests in neither inbox nor scheduler and
+                # silently lose them.
+                with self._state_lock:
+                    with self._lock:
+                        new = self._inbox
+                        self._inbox = []
+                    for req in new:
+                        self.scheduler.add_request(req)
 
-            if not self.scheduler.has_work():
-                # Park: deregister actors so we never wedge the Timekeeper
-                # barrier while idle; dispatcher arrivals wake us.  The park
-                # decision races with submit(): take the inbox lock so a
-                # concurrent submit either lands before (we skip parking) or
-                # after (its synchronous unpark re-registers us).
+                if not self.scheduler.has_work():
+                    # Park: deregister actors so we never wedge the
+                    # Timekeeper barrier while idle; dispatcher arrivals
+                    # wake us.  The park decision races with submit(): take
+                    # the inbox lock so a concurrent submit either lands
+                    # before (we skip parking) or after (its synchronous
+                    # unpark re-registers us).
+                    with self._lock:
+                        if self._inbox:
+                            continue
+                        self.runner.park()
+                    self._idle.set()
+                    with span("revati.engine.parked"):
+                        self._wake.wait(timeout=0.05)
+                    self._wake.clear()
+                    continue
                 with self._lock:
-                    if self._inbox:
-                        continue
-                    self.runner.park()
-                self._idle.set()
-                self._wake.wait(timeout=0.05)
-                self._wake.clear()
-                continue
-            with self._lock:
-                if self._killed.is_set():
-                    break                 # never re-register a dead replica
-                self.runner.unpark()
-            self._idle.clear()
+                    if self._killed.is_set():
+                        break             # never re-register a dead replica
+                    self.runner.unpark()
+                self._idle.clear()
 
             try:
                 self.step()
@@ -353,20 +379,22 @@ class LLMEngine:
 
     def step(self) -> List[Request]:
         """One engine iteration: schedule -> execute -> bookkeep."""
-        with self._state_lock:
+        with span("revati.engine.step", step=self._num_steps), \
+                self._state_lock:
             return self._step_locked()
 
     def _step_locked(self) -> List[Request]:
         cpu_t0 = time.monotonic()
         t_start = self.clock.now()
-        out = self.scheduler.schedule(t_start)
-        if out.is_empty:
-            # can happen under total memory pressure; let time flow
-            return []
-        for req in out.preempted:
-            release = getattr(self.runner, "release", None)
-            if release:
-                release(req.request_id)
+        with span("revati.engine.schedule"):
+            out = self.scheduler.schedule(t_start)
+            if out.is_empty:
+                # can happen under total memory pressure; let time flow
+                return []
+            for req in out.preempted:
+                release = getattr(self.runner, "release", None)
+                if release:
+                    release(req.request_id)
         cpu_sched = time.monotonic() - cpu_t0
         # snapshot batch composition BEFORE bookkeeping mutates request state
         n_prefill_tokens = sum(
@@ -374,27 +402,31 @@ class LLMEngine:
         n_decode = sum(1 for s in out.batch if not s.is_prefill)
 
         tokens = self.runner.execute(out)
+        # emulated runners keep no phases: their host and wait read zero
+        runner_host, runner_wait = getattr(self.runner, "last_phases",
+                                           (0.0, 0.0))
 
         cpu_t1 = time.monotonic()
         now = self.clock.now()
-        finished = self.scheduler.on_step_complete(out, tokens, now)
-        for req in finished:
-            release = getattr(self.runner, "release", None)
-            if release:
-                release(req.request_id)
-        if finished:
-            with self._live_lock:
-                for req in finished:
-                    self._live.pop(req.request_id, None)
-            if self.on_finish is not None:
-                self.on_finish(finished)
-            for fn in list(self.completion_listeners):
-                fn(finished)
-            with self._finish_cond:
-                self._finished_count += len(finished)
-                if self.retain_finished:
-                    self.finished.extend(finished)
-                self._finish_cond.notify_all()
+        with span("revati.engine.bookkeep"):
+            finished = self.scheduler.on_step_complete(out, tokens, now)
+            for req in finished:
+                release = getattr(self.runner, "release", None)
+                if release:
+                    release(req.request_id)
+            if finished:
+                with self._live_lock:
+                    for req in finished:
+                        self._live.pop(req.request_id, None)
+                if self.on_finish is not None:
+                    self.on_finish(finished)
+                for fn in list(self.completion_listeners):
+                    fn(finished)
+                with self._finish_cond:
+                    self._finished_count += len(finished)
+                    if self.retain_finished:
+                        self.finished.extend(finished)
+                    self._finish_cond.notify_all()
         cpu_post = time.monotonic() - cpu_t1
 
         self._num_steps += 1
@@ -409,6 +441,10 @@ class LLMEngine:
                 batch_size=len(out.batch),
                 cpu_overhead_wall=cpu_sched + cpu_post,
                 device_time=now - t_start,
+                sched_s=cpu_sched,
+                post_s=cpu_post,
+                runner_host_s=runner_host,
+                runner_wait_s=runner_wait,
             ))
         return finished
 

@@ -31,6 +31,7 @@ from repro.core.client import TimeJumpClient
 from repro.core.clock import VirtualClock
 from repro.core.emulation import VirtualDeviceContext
 from repro.core.predictor import BatchSpec, RuntimePredictor, SeqSpec
+from repro.core.spans import span
 
 from .scheduler import ScheduledSeq, SchedulerOutput
 
@@ -213,12 +214,17 @@ class RealModelRunner:
     the device has finished everything the step produced, is the step's
     real duration (recorded for predictor calibration).  The cache takes
     the weights' dtype.
+
+    ``execute`` runs in spans (``revati.runner.execute`` around
+    ``.prefill`` per prompt chunk, ``.feed``, ``.dispatch``, ``.sample``,
+    ``.wait``, ``.release``) and leaves ``last_phases``: its wall seconds
+    outside ``.wait`` and inside it, the host blocked on the device.  The
+    jitted programs are named ``prefill_chunk``, ``reset_slot``,
+    ``decode_step`` and ``sample`` in a profile.
     """
 
     def __init__(self, model, params, *, max_seqs: int, max_len: int,
                  clock: VirtualClock, chunk_buckets=(32, 64, 128, 256, 512)):
-        import functools
-
         import jax
         import jax.numpy as jnp
 
@@ -247,14 +253,24 @@ class RealModelRunner:
                                       window_slack=slack)
         self._slot_of: Dict[int, int] = {}
         self._free_slots = list(range(max_seqs))[::-1]
+        uniform = getattr(model, "uniform", "x")
+
+        def prefill_chunk(params, cache, slot, tokens, positions):
+            return _prefill_slot(model, params, cache, slot, tokens,
+                                 positions)
+
+        def reset_slot(cache, small, slot):
+            return _put_slot(uniform, cache, small, slot)
+
+        def sample(logits):
+            return jnp.argmax(logits, axis=-1)
+
         self._decode = jax.jit(model.decode_step, donate_argnums=(1,))
-        self._prefill = jax.jit(functools.partial(_prefill_slot, model),
-                                donate_argnums=(1,))
-        self._reset = jax.jit(
-            functools.partial(_put_slot, getattr(model, "uniform", "x")),
-            donate_argnums=(0,))
-        self._argmax = jax.jit(lambda logits: jnp.argmax(logits, axis=-1))
+        self._prefill = jax.jit(prefill_chunk, donate_argnums=(1,))
+        self._reset = jax.jit(reset_slot, donate_argnums=(0,))
+        self._sample = jax.jit(sample)
         self.samples: List[tuple] = []       # (BatchSpec, seconds) for fitting
+        self.last_phases = (0.0, 0.0)        # (host s, wait s) of the last step
 
     # ------------------------------------------------------------ warmup --
     def warmup(self) -> None:
@@ -273,9 +289,9 @@ class RealModelRunner:
                 pos = (self.max_len + np.arange(b, dtype=np.int32))[None]
                 logits, self.cache = self._prefill(
                     self.params, self.cache, slot, toks, pos)
-                self._argmax(logits)
+                self._sample(logits)
         logits = self.decode({})
-        self._jax.block_until_ready((self._argmax(logits), self.cache))
+        self._jax.block_until_ready((self._sample(logits), self.cache))
 
     # ------------------------------------------------------ device steps --
     def acquire(self, request_id: int) -> int:
@@ -310,52 +326,67 @@ class RealModelRunner:
         """One batched decode over every slot.  ``feeds`` maps slot ->
         (token, position); idle slots decode a throwaway token into the
         scratch region.  Returns logits (max_seqs, V)."""
-        tokens = np.zeros((self.max_seqs, 1), np.int32)
-        positions = np.full((self.max_seqs,), self.max_len, np.int32)
-        for slot, (tok, pos) in feeds.items():
-            tokens[slot, 0] = tok
-            positions[slot] = pos
-        self.cache["cache_len"] = self._jnp.asarray(positions)
-        logits, self.cache = self._decode(self.params, self.cache, tokens)
+        with span("revati.runner.feed"):
+            tokens = np.zeros((self.max_seqs, 1), np.int32)
+            positions = np.full((self.max_seqs,), self.max_len, np.int32)
+            for slot, (tok, pos) in feeds.items():
+                tokens[slot, 0] = tok
+                positions[slot] = pos
+            self.cache["cache_len"] = self._jnp.asarray(positions)
+        with span("revati.runner.dispatch"):
+            logits, self.cache = self._decode(self.params, self.cache, tokens)
         return logits
 
     # ------------------------------------------------------------ running --
     def execute(self, out: SchedulerOutput) -> Dict[int, int]:
         t0 = time.monotonic()
-        firsts = []                          # (request_id, device argmax)
-        for s in out.batch:
-            if not s.is_prefill:
-                continue
-            req = s.request
-            slot = self._slot_of.get(req.request_id)
-            if slot is None:
-                slot = self.acquire(req.request_id)
-            start = req.num_prefilled
-            chunk = req.prompt_tokens[start : start + s.num_new_tokens]
-            logits = self.prefill_chunk(slot, chunk, start)
-            if start + len(chunk) >= req.prompt_len:
-                firsts.append((req.request_id, self._argmax(logits)))
+        with span("revati.runner.execute"):
+            firsts = []                      # (request_id, device argmax)
+            for s in out.batch:
+                if not s.is_prefill:
+                    continue
+                with span("revati.runner.prefill"):
+                    req = s.request
+                    slot = self._slot_of.get(req.request_id)
+                    if slot is None:
+                        slot = self.acquire(req.request_id)
+                    start = req.num_prefilled
+                    chunk = req.prompt_tokens[start : start + s.num_new_tokens]
+                    logits = self.prefill_chunk(slot, chunk, start)
+                    if start + len(chunk) >= req.prompt_len:
+                        firsts.append((req.request_id, self._sample(logits)))
 
-        # The newest token is counted in context_len but not yet cached: it
-        # is fed at position context_len - 1.
-        decodes = [s.request for s in out.batch if not s.is_prefill]
-        feeds = {self._slot_of[r.request_id]:
-                 (r.output_tokens[-1], r.context_len - 1) for r in decodes}
-        picked = np.asarray(self._argmax(self.decode(feeds))) if feeds else ()
+            # The newest token is counted in context_len but not yet cached:
+            # it is fed at position context_len - 1.
+            decodes = [s.request for s in out.batch if not s.is_prefill]
+            feeds = {self._slot_of[r.request_id]:
+                     (r.output_tokens[-1], r.context_len - 1) for r in decodes}
+            picked = ()
+            if feeds:
+                logits = self.decode(feeds)
+                with span("revati.runner.sample"):
+                    picked = self._sample(logits)
 
-        tokens = {rid: int(np.asarray(t)[0]) for rid, t in firsts}
-        for r in decodes:
-            tokens[r.request_id] = int(picked[self._slot_of[r.request_id]])
-        self._jax.block_until_ready(self.cache)
-        dt = time.monotonic() - t0
-        self.samples.append((batch_spec_of(out), dt))
+            t_wait = time.monotonic()
+            with span("revati.runner.wait"):
+                picked = np.asarray(picked)
+                firsts = [(rid, int(np.asarray(t)[0])) for rid, t in firsts]
+                self._jax.block_until_ready(self.cache)
+            t_ready = time.monotonic()
+            tokens = dict(firsts)
+            for r in decodes:
+                tokens[r.request_id] = int(picked[self._slot_of[r.request_id]])
+            self.samples.append((batch_spec_of(out), t_ready - t0))
 
-        # release slots of finishing requests
-        for s in out.batch:
-            req = s.request
-            if (not s.is_prefill and
-                    req.num_generated + 1 >= req.max_new_tokens):
-                self.release(req.request_id)
+            # release slots of finishing requests
+            with span("revati.runner.release"):
+                for s in out.batch:
+                    req = s.request
+                    if (not s.is_prefill and
+                            req.num_generated + 1 >= req.max_new_tokens):
+                        self.release(req.request_id)
+        wait = t_ready - t_wait
+        self.last_phases = (time.monotonic() - t0 - wait, wait)
         return tokens
 
     def release(self, request_id: int) -> None:

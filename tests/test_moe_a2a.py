@@ -57,6 +57,10 @@ def test_moe_a2a_matches_ragged():
                           # probes cloud instance metadata over the network
                           # (30 slow retries) before falling back — a
                           # multi-minute flaky hang in the sanitised env
-                          "JAX_PLATFORMS": "cpu"})
+                          "JAX_PLATFORMS": "cpu",
+                          # the minimal env drops the repo conftest's
+                          # no-bytecode guard: keep the child from writing
+                          # __pycache__ dirs that test_hygiene rejects
+                          "PYTHONDONTWRITEBYTECODE": "1"})
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "MOE_A2A_OK" in proc.stdout

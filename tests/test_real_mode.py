@@ -4,6 +4,9 @@
 * the real stack serves every request, prompts off the bucket sizes
   included, and its greedy tokens are the cache-free model's;
 * the runner's cache takes the weights' dtype;
+* each step record splits the step into the engine's and the runner's
+  phases, which emulated runners leave at zero, and the runner's programs
+  carry stable names;
 * chip_smoke's phases pass at small sizes (kernels in interpret mode);
 * an exception in the engine loop reaches ``BenchmarkRunner.run`` at once.
 """
@@ -20,6 +23,7 @@ import pytest
 
 from repro.configs import get_reduced_config
 from repro.core.clock import VirtualClock
+from repro.core.predictor import StaticPredictor
 from repro.core.hardware import chip_of_device_kind
 from repro.kernels import ops
 from repro.launch import serve
@@ -90,6 +94,73 @@ def test_real_runner_cache_follows_weight_dtype():
     assert layers["k"].dtype == layers["v"].dtype == jnp.bfloat16
     # one (BatchSpec, seconds) sample per executed step
     assert len(stack.runner.samples) == len(stack.engine.step_log)
+
+
+def test_step_records_split_each_step_into_phases():
+    model, params = _model(jnp.float32)
+    res, stack = _serve(model, params, _requests(model.cfg.vocab_size))
+    assert res.num_requests == len(PROMPT_LENS)
+    log = stack.engine.step_log
+    assert len(log) == len(stack.runner.samples)
+    for rec, (_, dt) in zip(log, stack.runner.samples):
+        assert rec.sched_s > 0 and rec.post_s > 0
+        assert rec.sched_s + rec.post_s == rec.cpu_overhead_wall
+        assert rec.runner_host_s > 0 and rec.runner_wait_s >= 0
+        # execute sits inside the step's clock span, after scheduling
+        assert rec.runner_host_s + rec.runner_wait_s <= rec.device_time
+        # the sample ends where the wait ends; the slot release follows
+        assert dt <= rec.runner_host_s + rec.runner_wait_s
+        assert rec.runner_wait_s <= dt
+    assert stack.runner.last_phases == (log[-1].runner_host_s,
+                                        log[-1].runner_wait_s)
+
+
+@pytest.mark.parametrize("mode", ["emulate", "sleep"])
+def test_emulated_runners_leave_the_runner_phases_at_zero(mode):
+    cfg = get_reduced_config(ARCH)
+    stack = build_stack(cfg, EngineConfig(max_num_seqs=4,
+                                          max_batched_tokens=64,
+                                          block_size=16, num_blocks=256),
+                        mode, predictor=StaticPredictor(1e-3),
+                        use_worker_group=False)
+    reqs = _requests(cfg.vocab_size)
+    try:
+        res = BenchmarkRunner(stack.engine, reqs,
+                              transport=stack.transport).run(timeout=120)
+    finally:
+        stack.shutdown()
+    assert res.num_requests == len(reqs)
+    assert stack.engine.step_log
+    for rec in stack.engine.step_log:
+        assert rec.runner_host_s == rec.runner_wait_s == 0.0
+        assert rec.sched_s + rec.post_s == rec.cpu_overhead_wall
+
+
+def test_runner_programs_lower_under_stable_names():
+    """A profile names each program after its function; the benchmark's
+    readers key the batched decode on ``decode_step``, which no other
+    program's name holds."""
+    model, params = _model(jnp.float32)
+    stack = build_stack(model.cfg, EngineConfig(max_num_seqs=2), "real",
+                        model=model, params=params, max_len=256)
+    r = stack.runner
+    slot = np.int32(0)
+    toks = np.zeros((1, 32), np.int32)
+    pos = np.arange(32, dtype=np.int32)[None]
+    logits = jnp.zeros((2, model.cfg.vocab_size), jnp.float32)
+    lowered = {
+        "prefill_chunk": r._prefill.lower(params, r.cache, slot, toks, pos),
+        "reset_slot": r._reset.lower(r.cache, r._empty, slot),
+        "sample": r._sample.lower(logits),
+        "decode_step": r._decode.lower(params, r.cache,
+                                       np.zeros((2, 1), np.int32)),
+    }
+    stack.shutdown()
+    for name, low in lowered.items():
+        text = low.as_text()
+        assert f"module @jit_{name} " in text, name
+        if name != "decode_step":
+            assert "decode_step" not in text.split("\n")[0]
 
 
 def test_smoke_consistency_phase_at_reduced_width():
