@@ -102,8 +102,6 @@ def build_cell(arch: str, shape_name: str, mesh: Mesh,
         cfg = cfg.replace(attn_scores_dtype="bfloat16")
     if "moe_a2a" in opts and cfg.moe is not None:
         cfg = cfg.replace(moe_impl="a2a")
-    if "kv_defer_append" in opts:
-        cfg = cfg.replace(kv_append="defer")
     shape = SHAPES[shape_name]
     model = build_model(cfg)
     params_sds = model.abstract_params(jnp.bfloat16)

@@ -102,13 +102,9 @@ class ModelConfig:
     frontend: Optional[str] = None  # None | "audio_frames" | "vision_patches"
     frontend_tokens: int = 0        # frames/patches prepended per sample
     # §Perf lowering knobs (EXPERIMENTS.md): dtype of materialized attention
-    # scores in the dense lowering, the MoE execution strategy, and the
-    # KV-append strategy (defer = one post-stack scatter for all layers via
-    # two-segment online-softmax attention, instead of a full per-layer
-    # cache rewrite inside the scan carry).
+    # scores in the dense lowering, and the MoE execution strategy.
     attn_scores_dtype: str = "float32"   # float32 | bfloat16
     moe_impl: str = "ragged"             # ragged | a2a (shard_map EP)
-    kv_append: str = "inline"            # inline | defer
 
     # ------------------------------------------------------------ derived --
     def __post_init__(self):

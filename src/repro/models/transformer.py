@@ -18,6 +18,12 @@ Layer iteration strategy:
 * mixed patterns — an unrolled Python loop over per-layer parameter trees
   (RecurrentGemma's 26 layers compile fine unrolled).
 
+KV append: a decoder-only stack with a cache attends over the cache as it
+was plus the new tokens (an exact two-segment online-softmax merge) and
+appends every layer's new K/V after the stack, in one scatter into the
+donated cache, so no step rewrites or copies the cache.  The enc-dec
+decoder writes each layer's K/V before attending.
+
 KV cache layout (``extend`` mode):
 
 * attention layers: ``k``/``v`` of shape (L, B, S, Hkv, D) plus a shared
@@ -121,8 +127,14 @@ def run_block(
     *,
     enc_kv: Optional[Tuple] = None,   # cross-attention K/V (enc-dec decoder)
     cross_p: Optional[Dict] = None,
+    inline_kv: bool = False,
 ) -> Tuple[jax.Array, Optional[Dict], Dict]:
-    """One residual block.  Returns (y, new_cache, aux)."""
+    """One residual block.  Returns (y, new_cache, aux).
+
+    With a cache, an attention block returns only its new K/V
+    (``{"k_new", "v_new"}``) for ``_apply_deferred_append``; with
+    ``inline_kv`` it writes them into its layer of the cache first and
+    returns the whole rewritten layer."""
     aux: Dict[str, Any] = {}
     new_cache: Optional[Dict] = None
     h = L.apply_norm(cfg, x, p["norm1"])
@@ -134,23 +146,7 @@ def run_block(
             mask = L.causal_mask(positions, positions, window)
             ctx = L.attention(q, k_new, v_new, mask,
                               scores_dtype=_scores_dtype(cfg))
-        elif cfg.kv_append == "defer":
-            # §Perf "kv_defer_append": attend over [stale cache ‖ new chunk]
-            # via an exact two-segment online-softmax merge; the cache write
-            # happens ONCE for all layers after the stack (one in-place
-            # scatter) instead of a full per-layer cache rewrite inside the
-            # scan carry.  Unwritten/stale slots are masked by kv_pos tags.
-            mask_c = L.causal_mask(positions, cache["kv_pos"], window)
-            mask_s = L.causal_mask(positions, positions, window)
-            sd = _scores_dtype(cfg)
-            seg_c = L.attention_partial(q, cache["k"], cache["v"], mask_c,
-                                        scores_dtype=sd)
-            seg_s = L.attention_partial(q, k_new, v_new, mask_s,
-                                        scores_dtype=sd)
-            ctx = L.attention_merge2(seg_c, seg_s, x.dtype)
-            new_cache = {"k_new": k_new.astype(cache["k"].dtype),
-                         "v_new": v_new.astype(cache["v"].dtype)}
-        else:
+        elif inline_kv:
             S = cache["k"].shape[1]
             B, T = positions.shape
             widx = positions % S                                   # ring or linear
@@ -162,6 +158,23 @@ def run_block(
             ctx = L.attention(q, k_c, v_c, mask,
                               scores_dtype=_scores_dtype(cfg))
             new_cache = {"k": k_c, "v": v_c, "kv_pos": kv_pos}
+        else:
+            # attend over [cache as it was ‖ new tokens] via an exact
+            # two-segment online-softmax merge; the new K/V land in the
+            # cache after the stack.  Exact as long as a new token's
+            # position is not already tagged in the cache: real positions
+            # are written once per slot (the runner clears a slot before
+            # reuse); only the throwaway scratch rows are written again.
+            mask_c = L.causal_mask(positions, cache["kv_pos"], window)
+            mask_s = L.causal_mask(positions, positions, window)
+            sd = _scores_dtype(cfg)
+            seg_c = L.attention_partial(q, cache["k"], cache["v"], mask_c,
+                                        scores_dtype=sd)
+            seg_s = L.attention_partial(q, k_new, v_new, mask_s,
+                                        scores_dtype=sd)
+            ctx = L.attention_merge2(seg_c, seg_s, x.dtype)
+            new_cache = {"k_new": k_new.astype(cache["k"].dtype),
+                         "v_new": v_new.astype(cache["v"].dtype)}
         x = x + L.attn_out(p["attn"], ctx)
         if enc_kv is not None:
             hx = L.apply_norm(cfg, x, cross_p["norm"])
@@ -216,8 +229,8 @@ def _apply_deferred_append(cache_layers, new_kv, positions, *,
 
     cache_layers: {"k": (L,B,S,H,D), "v": ..., "kv_pos": (L,B,S)} (or without
     the leading L when ``layer_axis=False``); new_kv: {"k_new": (L,B,T,H,D),
-    "v_new": ...}.  The scatter targets are donated scan carries, so XLA
-    updates them in place — traffic is the T new tokens, not the cache.
+    "v_new": ...}.  The scatter's target is the donated cache, so XLA
+    updates it in place: the traffic is the T new tokens, not the cache.
     """
     k, v, kv_pos = cache_layers["k"], cache_layers["v"], cache_layers["kv_pos"]
     S = k.shape[2] if layer_axis else k.shape[1]
@@ -328,11 +341,9 @@ class TransformerLM:
                 new_cache = None
             else:
                 x, (new_layers, auxs) = jax.lax.scan(body, x, xs)
-                if (cfg.kv_append == "defer"
-                        and kind in ("attn", "local_attn")):
-                    # one in-place scatter appends every layer's new KV —
-                    # the scan carry never rewrote the cache (§Perf
-                    # "kv_defer_append")
+                if kind in ("attn", "local_attn"):
+                    # the scan returned each layer's new K/V only: one
+                    # scatter appends them all to the donated cache
                     new_layers = _apply_deferred_append(
                         cache["layers"], new_layers, positions)
                 new_cache = {"layers": new_layers}
@@ -343,9 +354,8 @@ class TransformerLM:
                 cache_l = cache["layers"][i] if cache is not None else None
                 x, new_cache_l, aux = run_block(
                     cfg, kind, params["blocks"][i], x, positions, cache_l)
-                if (cfg.kv_append == "defer" and new_cache_l is not None
-                        and "k_new" in new_cache_l):
-                    # unrolled path: apply immediately (no carry to save)
+                if new_cache_l is not None and "k_new" in new_cache_l:
+                    # unrolled: append at once (no scan output to save)
                     new_cache_l = _apply_deferred_append(
                         cache_l, new_cache_l, positions, layer_axis=False)
                 new_layers.append(new_cache_l)
@@ -467,8 +477,6 @@ class EncDecLM:
 
     def __init__(self, cfg: ModelConfig):
         assert cfg.encoder is not None
-        if cfg.kv_append == "defer":
-            cfg = cfg.replace(kv_append="inline")  # enc-dec keeps inline
         self.cfg = cfg
 
     def init(self, key, dtype=jnp.float32) -> PyTree:
@@ -529,7 +537,7 @@ class EncDecLM:
             p_l, cp_l, cache_l, (ek, ev) = scanned
             h, new_cache_l, _ = run_block(
                 cfg, "attn", p_l, h, positions, cache_l,
-                enc_kv=(ek, ev), cross_p=cp_l)
+                enc_kv=(ek, ev), cross_p=cp_l, inline_kv=True)
             return h, new_cache_l
 
         if remat:

@@ -13,6 +13,8 @@ while modules are imported would make test collection differ between
 workers.
 """
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -86,20 +88,46 @@ def test_ssd_scan_compiles_for_v5e(one_chip):
     _compile(lambda *a: ssd_scan(*a, chunk=128), xdt, dA, bc, bc)
 
 
-def test_qwen2_5_3b_decode_step_fits_one_v5e(one_chip):
-    """The full-width decode step over the real-mode runner's cache: 8
-    slots × 2048 positions plus the 512-position prefill scratch region."""
-    model = build_model(get_config("qwen2_5_3b"))
+def _compiled_decode_step(one_chip, arch, slots):
+    """The donated decode step over the real-mode runner's cache (2048
+    positions a slot plus the 512-position prefill scratch region),
+    compiled for one v5e; returns it with the cache's shapes."""
+    model = build_model(get_config(arch))
     with_sharding = lambda t: jax.tree.map(          # noqa: E731
         lambda x: _spec(x.shape, x.dtype, one_chip), t)
     params = with_sharding(model.abstract_params(jnp.bfloat16))
     cache = with_sharding(jax.eval_shape(
-        lambda: model.init_cache(8, 2048, jnp.bfloat16, window_slack=512)))
-    tokens = _spec((8, 1), jnp.int32, one_chip)
+        lambda: model.init_cache(slots, 2048, jnp.bfloat16, window_slack=512)))
+    tokens = _spec((slots, 1), jnp.int32, one_chip)
     compiled = jax.jit(model.decode_step, donate_argnums=(1,)).lower(
         params, cache, tokens).compile()
+    return compiled, cache
+
+
+def test_qwen2_5_3b_decode_step_fits_one_v5e(one_chip):
+    """The full-width decode step over 8 slots."""
+    compiled, _ = _compiled_decode_step(one_chip, "qwen2_5_3b", 8)
     mem = compiled.memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert mem.argument_size_in_bytes > 6e9          # 3.09 B bf16 params
     assert need < V5E_HBM_BYTES, need
+
+
+@pytest.mark.parametrize("arch, slots", [("qwen2_5_3b", 32), ("olmo_1b", 16)])
+def test_decode_step_appends_kv_in_place_on_v5e(one_chip, arch, slots):
+    """The benchmark cells' decode step writes each step's K/V into the
+    donated cache: no temporary near the cache's size, and no copy or
+    dynamic-update-slice of the whole stacked K or V, which a layer scan
+    returning rewritten layers would need."""
+    compiled, cache = _compiled_decode_step(one_chip, arch, slots)
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(cache))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 0.02 * cache_bytes, (temp, cache_bytes)
+
+    stacked = "bf16[" + ",".join(map(str, cache["layers"]["k"].shape)) + "]"
+    whole = re.compile(r"%(\S+) = " + re.escape(stacked) + r"\{[^}]*\} (\S+)\(")
+    passes = [(name, op) for name, op in whole.findall(compiled.as_text())
+              if op in ("copy", "dynamic-update-slice")
+              or op == "fusion" and re.search(r"copy|dynamic-update-slice", name)]
+    assert not passes, passes
